@@ -44,7 +44,17 @@ __all__ = [
     "ConvDataflowChoice",
     "autotune_tile",
     "digit_cache_bytes",
+    "DIGIT_CACHE_BUDGET_BYTES",
 ]
+
+# Decoded-digit strips larger than this fall back to per-step decode in
+# the kernel (kernel.py cache_digits=False); see DESIGN.md §2.2.  Tiles
+# are budgeted against the kernel's scoped VMEM limit less this strip.
+DIGIT_CACHE_BUDGET_BYTES = 4 * 2**20
+
+
+def _default_vmem_budget(hw: HW) -> float:
+    return hw.vmem_scoped_bytes - DIGIT_CACHE_BUDGET_BYTES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +156,9 @@ def vmem_working_set(
     BRAM_partial-sums -> accumulator tile(s); BRAM_activations -> int8 act
     tile; BRAM_weights -> packed digit-plane tile.  The paper's N/w_Q
     factor appears as the packed-weight byte count (bk * w_Q/8 per column).
+    The kernel's own temporaries count too — the int32 digit fields of
+    one plane's decode and the (bm, P*bn) int32 dot result — since the
+    TPU compiler places them in the same scoped VMEM.
     """
     p = fmt.planes
     f = fmt.digits_per_byte
@@ -155,7 +168,10 @@ def vmem_working_set(
     accs = (p if variant == "sa" else 1) * tile.bm * tile.bn * 4
     out = tile.bm * tile.bn * 4
     scales = 2 * tile.bn * 8                     # gamma + colsum blocks
-    return 2 * (act + wgt) + dig + accs + out + scales  # 2x: double buffering
+    decode = 2 * tile.bk * tile.bn * 4           # int32 fields + digits
+    partial = tile.bm * p * tile.bn * 4          # int32 dot result
+    # 2x: double buffering of every pipelined block.
+    return 2 * (act + wgt + out + scales) + dig + accs + decode + partial
 
 
 def tile_utilization(g: Gemm, tile: TileCandidate) -> float:
@@ -285,7 +301,7 @@ def choose_conv_dataflow(
     the im2col dataflow sweeps the full (bm, bk, bn) grid (any GEMM tile
     is realizable on the patch matrix).  With ``pin_tile`` (the pallas
     implicit kernel) the implicit dataflow pins bm = Wo (one output row
-    per tile) and bk = C (one kernel position per K step) — the
+    per tile) and bk = C (one kernel position per dot) — the
     structure of conv_kernel.py — and sweeps bn; a 3-channel stem is
     correctly penalized for starving the MXU's K lanes.  Without it
     (the XLA direct conv, which tiles internally) implicit sweeps the
@@ -293,7 +309,7 @@ def choose_conv_dataflow(
     implicit (no patch buffer to allocate).
     """
     budget = (vmem_budget if vmem_budget is not None
-              else 0.5 * hw.vmem_bytes)
+              else _default_vmem_budget(hw))
     fmt = PlaneFormat(w_bits=w_bits, k=k, k_dim=conv.k)
     best: Dict[str, Tuple[float, Optional[TileCandidate]]] = {
         "im2col": (math.inf, None), "implicit": (math.inf, None)}
@@ -357,7 +373,8 @@ def choose_tile(
     vmem_budget: Optional[float] = None,
 ) -> DseChoice:
     """Red box: pick (bm,bk,bn) minimizing the model's roofline time."""
-    budget = vmem_budget if vmem_budget is not None else 0.5 * hw.vmem_bytes
+    budget = (vmem_budget if vmem_budget is not None
+              else _default_vmem_budget(hw))
     fmt_inner = PlaneFormat(w_bits=w_bits, k=k, k_dim=1)
     fmt_bound = PlaneFormat(w_bits=8, k=min(k, 8), k_dim=1)
     best: Optional[DseChoice] = None

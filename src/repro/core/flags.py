@@ -15,77 +15,7 @@ import threading
 _local = threading.local()
 
 __all__ = ["dryrun_unroll", "force_unroll", "scan_unroll_arg",
-           "default_interpret", "SERVING_XLA_FLAGS", "serving_xla_flags"]
-
-# Latency-hiding / async-collective XLA options for serving launches:
-# overlap collective permute + all-gather with compute and fuse the
-# softmax/GEMM epilogues — the standard high-throughput inference set.
-# NOT harmless on unknown builds: XLA ABORTS the process on flags its
-# build doesn't define (parse_flags_from_env checks strictly), and the
-# set varies across jaxlib versions — so serving_xla_flags() probes the
-# local build in a subprocess and drops what it rejects.
-SERVING_XLA_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true",
-    "--xla_gpu_triton_gemm_any=True",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
-
-
-def _xla_accepted_flags(candidates):
-    """The subset of ``candidates`` the local XLA build parses.
-
-    One throwaway ``import jax; jax.devices()`` subprocess with the
-    candidates in XLA_FLAGS: success keeps them all; on the strict-parse
-    abort, the 'Unknown flags in XLA_FLAGS: ...' message names the
-    rejects.  An unparseable failure keeps NONE (never break the launch
-    for an optimization flag).
-    """
-    import os
-    import re
-    import subprocess
-    import sys
-
-    env = dict(os.environ, XLA_FLAGS=" ".join(candidates))
-    try:
-        r = subprocess.run(
-            [sys.executable, "-c", "import jax; jax.devices()"],
-            env=env, capture_output=True, text=True, timeout=300)
-    except (OSError, subprocess.TimeoutExpired):
-        return []
-    if r.returncode == 0:
-        return list(candidates)
-    m = re.search(r"Unknown flags in XLA_FLAGS:([^\n]*)", r.stderr)
-    if not m:
-        return []
-    unknown = {t.split("=", 1)[0] for t in m.group(1).split()}
-    keep = [f for f in candidates if f.split("=", 1)[0] not in unknown]
-    # The reject list could itself be stale — re-verify the survivors.
-    return _xla_accepted_flags(keep) if keep else []
-
-
-def serving_xla_flags(existing: str | None = None,
-                      probe: bool = True) -> str:
-    """Compose ``XLA_FLAGS`` for a serving process.
-
-    Appends each serving flag to ``existing`` (default: the current
-    ``XLA_FLAGS`` env var) unless the variable already sets that option —
-    a user's explicit choice always wins.  With ``probe`` (the default),
-    flags the local XLA build rejects are dropped via a subprocess
-    check.  Returns the new flag string; the caller assigns it to
-    ``os.environ`` BEFORE the first backend initialization (flags lock
-    with the backend, like device counts).
-    """
-    import os
-
-    base = os.environ.get("XLA_FLAGS", "") if existing is None else existing
-    parts = base.split()
-    have = {p.split("=", 1)[0] for p in parts}
-    new = [f for f in SERVING_XLA_FLAGS if f.split("=", 1)[0] not in have]
-    if probe and new:
-        new = _xla_accepted_flags(new)
-    return " ".join(parts + new)
+           "default_interpret", "enable_compile_cache"]
 
 
 def default_interpret() -> bool:
@@ -99,6 +29,29 @@ def default_interpret() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is overridden.  Otherwise the cache goes to ``.jax_cache`` at
+    the root of the checkout — a fixed path, because the path is part of
+    the cache key (a per-run temporary directory would never hit).
+    Called by entry points (``chip_smoke.py``, ``launch/serve.py``),
+    never at import.  Returns the directory in use.
+    """
+    import os
+    import pathlib
+
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def dryrun_unroll() -> bool:

@@ -192,11 +192,6 @@ def packed_weight_bytes(k_dim: int, n_dim: int, w_bits: int, k: int) -> int:
     return fmt.planes * fmt.packed_k * n_dim
 
 
-def plane_shift_weights(fmt: PlaneFormat, dtype=jnp.int32) -> jax.Array:
-    """2^{k p} combination weights for the Sum-Together adder tree."""
-    return (2 ** (fmt.k * jnp.arange(fmt.planes))).astype(dtype)
-
-
 def random_codes(rng: np.random.Generator, shape: Tuple[int, ...], w_bits: int) -> np.ndarray:
     """Uniform signed codes for tests/benchmarks."""
     lo, hi = -(2 ** (w_bits - 1)), 2 ** (w_bits - 1) - 1

@@ -48,6 +48,7 @@ class HW:
     ici_links: int           # usable links per chip (2D torus: 4)
     hbm_bytes: float         # HBM capacity per chip
     vmem_bytes: float        # VMEM capacity per core
+    vmem_scoped_bytes: float  # scoped VMEM a Mosaic kernel gets by default
 
     @property
     def ici_bw_per_chip(self) -> float:
@@ -65,6 +66,9 @@ TPU_V5E = HW(
     ici_links=4,
     hbm_bytes=16 * 2**30,
     vmem_bytes=128 * 2**20,
+    # The limit the TPU compiler enforces on a pallas_call that sets no
+    # vmem_limit_bytes ("Scoped allocation ... limit 16.00M" on v5e).
+    vmem_scoped_bytes=16 * 2**20,
 )
 
 _DTYPE_BYTES = {
@@ -297,8 +301,6 @@ def roofline_from_compiled(
     mpmm planes), which executes at 2x the bf16 rate on v5e.
     """
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     bts = float(ca.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

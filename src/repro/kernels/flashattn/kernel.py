@@ -102,7 +102,7 @@ def _fwd_kernel_packed(q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
     Refs (VMEM blocks):
       q_ref: (block_q, d)
       kp_ref/vp_ref: (P, seq_k, packed_d) uint8 digit planes
-      ks_ref/kz_ref/vs_ref/vz_ref: (seq_k,) f32 per-token scale / zero
+      ks_ref/kz_ref/vs_ref/vz_ref: (1, seq_k) f32 per-token scale / zero
       o_ref: (block_q, d)
     """
     qb = pl.program_id(2)
@@ -126,12 +126,13 @@ def _fwd_kernel_packed(q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
         acc, m, l = carry
         kdig = digits_of(
             kp_ref[:, pl.dslice(kb * block_k, block_k), :], k_slice)
-        ks = ks_ref[pl.dslice(kb * block_k, block_k)]
-        kz = kz_ref[pl.dslice(kb * block_k, block_k)]
+        ks = ks_ref[0, pl.dslice(kb * block_k, block_k)]
+        kz = kz_ref[0, pl.dslice(kb * block_k, block_k)]
         s_codes = jnp.zeros((block_q, block_k), jnp.float32)
         for p_i in range(kdig.shape[0]):                  # static unroll
             s_codes += float(1 << (k_slice * p_i)) * jax.lax.dot_general(
-                q, kdig[p_i], (((1,), (1,)), ((), ())),
+                q, jax.lax.index_in_dim(kdig, p_i, keepdims=False),
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         s = s_codes * ks[None, :] + q_sum[:, None] * kz[None, :]
 
@@ -149,14 +150,15 @@ def _fwd_kernel_packed(q_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref,
 
         vdig = digits_of(
             vp_ref[:, pl.dslice(kb * block_k, block_k), :], v_slice)
-        vs = vs_ref[pl.dslice(kb * block_k, block_k)]
-        vz = vz_ref[pl.dslice(kb * block_k, block_k)]
+        vs = vs_ref[0, pl.dslice(kb * block_k, block_k)]
+        vz = vz_ref[0, pl.dslice(kb * block_k, block_k)]
         # p . (code*s + z): fold the V scale into p, zero-term is rank-1.
         pw = p * vs[None, :]
         pv = jnp.zeros((block_q, head_dim), jnp.float32)
         for p_i in range(vdig.shape[0]):
             pv += float(1 << (v_slice * p_i)) * jax.lax.dot_general(
-                pw, vdig[p_i], (((1,), (0,)), ((), ())),
+                pw, jax.lax.index_in_dim(vdig, p_i, keepdims=False),
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         pv += jnp.sum(p * vz[None, :], axis=-1)[:, None]
         acc_new = acc * alpha[:, None] + pv
@@ -208,7 +210,11 @@ def flash_fwd_packed(
         causal=causal, window=window, q_offset=q_offset, softmax_scale=scale,
         k_slice=k_slice, v_slice=v_slice, head_dim=d)
 
-    seq_spec = pl.BlockSpec((None, None, sk), lambda ib, ih, iq: (ib, ih, 0))
+    # Per-token scales ride as (B, H, 1, Sk) so the block's last two dims
+    # equal the array's — Mosaic's (8, 128) block rule.
+    ks, kz, vs, vz = (t[:, :, None, :] for t in (ks, kz, vs, vz))
+    seq_spec = pl.BlockSpec((None, None, 1, sk),
+                            lambda ib, ih, iq: (ib, ih, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(b, h, sq // block_q),

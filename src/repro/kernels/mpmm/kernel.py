@@ -8,7 +8,7 @@ Hardware mapping (DESIGN.md §2):
   * PPG operand slice k      ->  digit-plane width of the packed weights;
     all P planes feed ONE MXU contraction per grid step — the plane axis
     is folded into the N axis of the dot and the 2^{kp} shifts applied
-    post-dot (``plane_shift_weights``), so a step costs one
+    post-dot (``_shift_add``), so a step costs one
     (bm, bk) @ (bk, P*bn) int8 pass instead of P sequential passes.
   * Sum-Together adder tree  ->  one int32 accumulator tile, shift-add
     across planes (`variant='st'`).
@@ -46,11 +46,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import flags
-from repro.core.packing import PlaneFormat, plane_shift_weights
+from repro.core.packing import PlaneFormat
+from repro.core.roofline import TPU_V5E
 from repro.kernels.mpmm import epilogue as _epi
 from repro.kernels.mpmm.epilogue import EpilogueSpec
 
-__all__ = ["mpmm_pallas"]
+__all__ = ["mpmm_pallas", "VMEM_LIMIT_BYTES"]
+
+# Scoped VMEM each kernel asks the TPU compiler for: half of v5e's
+# 128 MiB.  Tiles are budgeted (core/dse) against the 16 MiB a kernel
+# gets by default, but the working-set model leaves out the compiler's
+# own temporaries (int32 digit fields, sublane padding of the stacked
+# fields, operand relayouts): compiles at the edge of that budget
+# allocated up to 2.5x the model's figure.  The headroom keeps every
+# budget-feasible tile compilable without shrinking it per call.
+VMEM_LIMIT_BYTES = int(0.5 * TPU_V5E.vmem_bytes)
 
 
 def _decode_block(w_u8: jax.Array, fmt: PlaneFormat, bk: int) -> jax.Array:
@@ -59,24 +69,49 @@ def _decode_block(w_u8: jax.Array, fmt: PlaneFormat, bk: int) -> jax.Array:
     Digits are interleaved 8//k per byte along K (core/packing.pack_bits):
     K index = byte_index * f + field_index.  Plane p occupies columns
     [p*bn, (p+1)*bn) of the result, ready for the fused contraction.
+
+    Planes are split with static ``lax.index_in_dim`` slices: Mosaic has
+    no lowering for the ``dynamic_slice`` that integer indexing of a
+    value traces to.  Each field is one shift pair — logical for the
+    unsigned lower planes, arithmetic (sign-extending, paper Fig. 1b)
+    for the top plane — and each plane narrows to int8 before the
+    concatenation, which keeps the int32 temporaries to one plane's
+    fields: the compiler holds them all in the kernel's scoped VMEM.
     """
     f = fmt.digits_per_byte
     k = fmt.k
-    mask = (1 << k) - 1
-    w32 = w_u8.astype(jnp.int32)  # (P, bkp, bn)
-    fields = [(w32 >> (k * i)) & mask for i in range(f)]
-    # (P, bkp, f, bn) -> (P, bk, bn): field index is minor within a byte.
-    digits = jnp.stack(fields, axis=2).reshape(w32.shape[0], bk, w32.shape[-1])
-    # Sign-extend the top plane (two's-complement, paper Fig. 1b).
+    bn = w_u8.shape[-1]
     top_bits = fmt.w_bits - fmt.k * (fmt.planes - 1)
-    sign_bit = 1 << (top_bits - 1)
-    top = digits[-1] & ((1 << top_bits) - 1)
-    top = jnp.where(top >= sign_bit, top - (1 << top_bits), top)
-    digits = jnp.concatenate([digits[:-1], top[None]], axis=0)
-    # (P, bk, bn) -> (bk, P*bn): fold the plane axis into N for the dot.
-    return jnp.concatenate(
-        [digits[p] for p in range(fmt.planes)], axis=-1
-    ).astype(jnp.int8)
+    planes = []
+    for p in range(fmt.planes):
+        w32 = jax.lax.index_in_dim(w_u8, p, axis=0,
+                                   keepdims=False).astype(jnp.int32)
+        bits = top_bits if p == fmt.planes - 1 else k
+        # Field i holds bits [k*i, k*i + bits): move its top bit to bit
+        # 31, then shift back down (arithmetic only for the top plane).
+        if p == fmt.planes - 1:
+            fields = [(w32 << (32 - k * i - bits)) >> (32 - bits)
+                      for i in range(f)]
+        else:
+            fields = [(w32 >> (k * i)) & ((1 << k) - 1) for i in range(f)]
+        # (bkp, f, bn) -> (bk, bn): field index is minor within a byte.
+        dig = (fields[0] if f == 1
+               else jnp.stack(fields, axis=1).reshape(bk, bn))
+        planes.append(dig.astype(jnp.int8))
+    # (bk, P*bn): the plane axis folded into N for the dot.
+    return jnp.concatenate(planes, axis=-1)
+
+
+def _shift_add(partial: jax.Array, fmt: PlaneFormat, bn: int) -> jax.Array:
+    """(rows, P*bn) per-plane partials -> (rows, bn) sum_p 2^{kp} * plane p.
+
+    Static lane slices of the plane-major columns, the Sum-Together
+    adder tree; bit-exact integer arithmetic in any order.
+    """
+    acc = partial[:, :bn]
+    for p in range(1, fmt.planes):
+        acc = acc + partial[:, p * bn:(p + 1) * bn] * (1 << (fmt.k * p))
+    return acc
 
 
 def _fused_epilogue(acc, gamma_ref, colsum_ref, epi_refs, out_ref,
@@ -137,16 +172,14 @@ def _mpmm_kernel(
         preferred_element_type=jnp.int32,
     )                                   # (bm, P*bn) int32
     bm, bn = acc_ref.shape[-2], acc_ref.shape[-1]
-    part3 = partial.reshape(bm, fmt.planes, bn)
 
     if variant == "st":
         # Sum-Together: shift-add over planes into one accumulator.
-        shifts = plane_shift_weights(fmt)
-        acc_ref[...] += jnp.sum(part3 * shifts[None, :, None], axis=1)
+        acc_ref[...] += _shift_add(partial, fmt, bn)
     else:
         # Sum-Apart: partial sums stay apart, one accumulator per plane.
         for p in range(fmt.planes):
-            acc_ref[p] += part3[:, p, :]
+            acc_ref[p] += partial[:, p * bn:(p + 1) * bn]
 
     @pl.when(kk == n_k - 1)
     def _epilogue():
@@ -233,7 +266,7 @@ def mpmm_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda j, i, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # The digit cache makes M steps order-dependent (decode at
             # i == 0, reuse at i > 0), so i must be "arbitrary" while the
             # cache is on — a Megacore split of a "parallel" i would hand
@@ -242,6 +275,7 @@ def mpmm_pallas(
             dimension_semantics=(
                 ("parallel", "arbitrary", "arbitrary") if cache_digits
                 else ("parallel", "parallel", "arbitrary")),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(*operands)

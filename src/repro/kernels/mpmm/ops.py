@@ -53,10 +53,6 @@ __all__ = [
     "autotune_tile",
 ]
 
-# Decoded-digit strips larger than this fall back to per-step decode in
-# the kernel (kernel.py cache_digits=False); see DESIGN.md §2.2.
-DIGIT_CACHE_BUDGET_BYTES = 4 * 2**20
-
 
 @dataclasses.dataclass(frozen=True)
 class TileShape:
@@ -301,7 +297,7 @@ def mpmm(
                         k_dim=planes_p.shape[1] * f, signed=fmt.signed)
     tile_cand = _dse.TileCandidate(bm, bk, bn)
     cache = (_dse.digit_cache_bytes(fmt_p.k_dim, tile_cand, fmt_p)
-             <= DIGIT_CACHE_BUDGET_BYTES)
+             <= _dse.DIGIT_CACHE_BUDGET_BYTES)
     out = _kernel.mpmm_pallas(
         a_p, planes_p, gamma_p, colsum_p,
         fmt=fmt_p, act_zero=act_zero, tile=(bm, bk, bn), variant=variant,
@@ -450,7 +446,7 @@ def conv_mpmm(
     shift_p = _pad_to(shift, 1, bn) if shift is not None else None
     res_p = _pad_to(residual, 3, bn) if residual is not None else None
     n_k = kh * kw
-    cache = n_k * c * fmt.planes * bn <= DIGIT_CACHE_BUDGET_BYTES
+    cache = n_k * c * fmt.planes * bn <= _dse.DIGIT_CACHE_BUDGET_BYTES
     out = _conv_kernel.conv_mpmm_pallas(
         xp, planes_p, gamma_p, colsum_p,
         fmt=fmt, act_zero=act_zero, kh=kh, kw=kw, stride=stride,

@@ -16,14 +16,8 @@ __all__ = ["make_production_mesh", "make_local_mesh", "make_serve_mesh",
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the
-    ``AxisType`` enum itself) only exist on newer jax; older versions
-    get the same Auto-typed mesh by default."""
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -57,6 +57,7 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint import CheckpointStore
+from repro.core import flags
 from repro.core.plan import FrontierManifest, PrecisionPlan
 from repro.core.precision import PrecisionPolicy
 from repro.launch.mesh import make_serve_mesh, mesh_axes, parse_mesh_spec
@@ -298,11 +299,6 @@ def main(argv=None) -> int:
     ap.add_argument("--devices", type=int, default=None,
                     help="force N host CPU devices (placeholder topology; "
                          "must run before the first jax computation)")
-    ap.add_argument("--xla-serving-flags", action="store_true",
-                    help="apply the latency-hiding/async-collective "
-                         "XLA_FLAGS set (core.flags.SERVING_XLA_FLAGS) "
-                         "before backend init; flags already present in "
-                         "the environment are left untouched")
     ap.add_argument("--mesh", default=None,
                     help="serve mesh 'DATAxMODEL' (e.g. 8x1): shard the "
                          "packed tree + batch across local devices")
@@ -317,12 +313,7 @@ def main(argv=None) -> int:
                          "serve loop into DIR (TensorBoard-loadable)")
     args = ap.parse_args(argv)
 
-    if args.xla_serving_flags:
-        # Must run before the first backend initialization, same as
-        # --devices below: XLA flags lock with the backend.
-        from repro.core import flags as _flags
-        os.environ["XLA_FLAGS"] = _flags.serving_xla_flags()
-        print(f"[serve] XLA_FLAGS = {os.environ['XLA_FLAGS']}")
+    flags.enable_compile_cache()
     if args.devices:
         # Device count locks at the first backend initialization; jax is
         # imported but nothing has touched devices yet at this point.

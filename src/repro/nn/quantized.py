@@ -308,10 +308,16 @@ def qlinear_serve_apply(
 
 def im2col(x: jax.Array, kh: int, kw: int, stride: int, padding: str
            ) -> jax.Array:
-    """x (B,H,W,C) -> patches (B,H',W', kh*kw*C) matching HWIO weight layout."""
+    """x (B,H,W,C) -> patches (B,H',W', kh*kw*C) matching HWIO weight layout.
+
+    The patches are a convolution with a one-hot kernel; at the default
+    precision the TPU rounds f32 operands to bf16, which changed the
+    quantized codes of a float32 stem input.  HIGHEST keeps them exact.
+    """
     patches = jax.lax.conv_general_dilated_patches(
         x, (kh, kw), (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
     # conv_general_dilated_patches yields features ordered (C, kh, kw);
     # reorder to (kh, kw, C) so a reshape of HWIO weights lines up.
     b, ho, wo, f = patches.shape

@@ -165,10 +165,18 @@ class ImageServer:
             if self.mesh is None:
                 self._fns[bucket] = jax.jit(fn)
             else:
+                # XLA cannot partition a Mosaic kernel, so every device
+                # runs the whole graph on its own batch shard (images
+                # never mix, so this is the data-parallel program).
+                # check_vma=False: pallas_call outputs carry no
+                # varying-axes annotation.
                 rep = part.replicated(self.mesh)
                 dsh = NamedSharding(self.mesh, P("data"))
                 self._fns[bucket] = jax.jit(
-                    fn, in_shardings=(rep, dsh), out_shardings=dsh)
+                    jax.shard_map(fn, mesh=self.mesh,
+                                  in_specs=(P(), P("data")),
+                                  out_specs=P("data"), check_vma=False),
+                    in_shardings=(rep, dsh), out_shardings=dsh)
         return self._fns[bucket]
 
     def _bucket_for(self, n: int) -> int:

@@ -245,7 +245,7 @@ class TestDigitCache:
         tile = O.TileShape(32, 512, 128)
         strip = dse.digit_cache_bytes(8192, dse.TileCandidate(32, 512, 128),
                                       fmt)
-        assert strip > O.DIGIT_CACHE_BUDGET_BYTES, strip
+        assert strip > dse.DIGIT_CACHE_BUDGET_BYTES, strip
         y = O.mpmm(a, planes, gamma, colsum, fmt=fmt, impl="pallas",
                    tile=tile)
         y_ref = ref.mpmm_ref(a, planes, fmt, gamma, act_zero=128)

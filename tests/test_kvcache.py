@@ -278,27 +278,3 @@ class TestServingIntegration:
         gen_fp = Generator(api, pack_for_serving(api, train))
         sched_fp = GenerateScheduler(gen_fp, max_len=32, slots=2)
         assert sched_fp.stats()["kv_cache_compression"] == pytest.approx(1.0)
-
-
-class TestServingXLAFlags:
-    """Satellite: latency-hiding flag composition (probe-off paths)."""
-
-    def test_appends_to_existing(self):
-        from repro.core import flags
-        out = flags.serving_xla_flags("--foo=1", probe=False)
-        parts = out.split()
-        assert parts[0] == "--foo=1"
-        assert set(flags.SERVING_XLA_FLAGS) <= set(parts[1:])
-
-    def test_user_setting_wins(self):
-        from repro.core import flags
-        pinned = "--xla_gpu_enable_latency_hiding_scheduler=false"
-        out = flags.serving_xla_flags(pinned, probe=False)
-        assert out.count("xla_gpu_enable_latency_hiding_scheduler") == 1
-        assert pinned in out.split()
-
-    def test_idempotent(self):
-        from repro.core import flags
-        once = flags.serving_xla_flags("", probe=False)
-        twice = flags.serving_xla_flags(once, probe=False)
-        assert once == twice

@@ -236,3 +236,29 @@ class TestElasticRestore:
         assert leaf.sharding == NamedSharding(mesh, P())
         a = jax.tree.leaves(state["params"])[0]
         np.testing.assert_array_equal(np.asarray(a), np.asarray(leaf))
+
+
+class TestCompileCache:
+    """``flags.enable_compile_cache``: JAX_COMPILATION_CACHE_DIR wins,
+    else one fixed, gitignored directory inside the checkout."""
+
+    @pytest.mark.parametrize("given", [False, True], ids=["unset", "set"])
+    def test_cache_directory(self, given, tmp_path, monkeypatch):
+        from repro.core import flags
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        was = jax.config.jax_compilation_cache_dir
+        if given:
+            want = str(tmp_path / "cache")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        else:
+            want = os.path.join(root, ".jax_cache")
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            assert flags.enable_compile_cache() == want
+            assert flags.enable_compile_cache() == want  # fixed, not per call
+            assert jax.config.jax_compilation_cache_dir == (
+                was if given else want)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
