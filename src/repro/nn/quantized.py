@@ -24,6 +24,7 @@ resolution point of the layer namespace (DESIGN.md §7).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -242,6 +243,21 @@ def _fold_bias(p, epilogue, scale, shift):
     return epilogue, scale, shift
 
 
+def _layer_scope(fn):
+    """Trace ``fn`` under ``jax.named_scope(name)`` when it is given a
+    layer ``name``: every op it lowers to then carries the layer's name
+    in its ``op_name`` metadata, so a device trace's ops map back to
+    the plan's layers.  Kernel names are left as they are."""
+    @functools.wraps(fn)
+    def scoped(*args, name: str = "", **kw):
+        if not name:
+            return fn(*args, name=name, **kw)
+        with jax.named_scope(name):
+            return fn(*args, name=name, **kw)
+    return scoped
+
+
+@_layer_scope
 def qlinear_serve_apply(
     p: Dict[str, jax.Array],
     x: jax.Array,
@@ -382,6 +398,7 @@ def conv_serve_dataflow(x_shape, policy, *, k: int, stride: int,
     return choice.dataflow
 
 
+@_layer_scope
 def qconv_serve_apply(p, x, policy, *, k: int, stride: int = 1,
                       padding="SAME", layer_class: str = "inner",
                       tile: Optional[mpmm_ops.TileShape] = None,

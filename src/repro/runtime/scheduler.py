@@ -120,6 +120,7 @@ class Ticket:
     plan_point: str = ""                # frontier point that served it
     retries: int = 0
     note: str = ""                      # diagnostic detail for failures
+    batch: Optional[int] = None         # traced dispatch that served it
 
     @property
     def queue_wait_s(self) -> Optional[float]:
@@ -271,7 +272,9 @@ class _SchedulerBase:
             tr.span_at("queue", ticket.t_submit, ticket.t_admit,
                        cat="request", tid=tid)
             tr.span_at("serve", ticket.t_admit, ticket.t_done,
-                       cat="request", tid=tid)
+                       cat="request", tid=tid,
+                       args=None if ticket.batch is None
+                       else {"batch": ticket.batch})
 
     def _check_not_terminal(self, ticket: Ticket) -> None:
         """A ticket terminates exactly once — double completion is a
@@ -449,6 +452,7 @@ class ImageScheduler(_SchedulerBase):
         self.buckets = tuple(sorted(server.batch_buckets))
         self.dispatched_batches: Deque[int] = collections.deque(
             maxlen=history)
+        self._batch_ids = itertools.count()
         # Expected request shape: from the server's model config when it
         # carries one (ImageServer), else locked to the first request.
         cfg = getattr(getattr(server, "api", None), "cfg", None)
@@ -490,12 +494,40 @@ class ImageScheduler(_SchedulerBase):
             t.t_admit = now
         self._log("dispatch", batch)
         self.dispatched_batches.append(take)
-        logits = np.asarray(self.server.predict(
+        if self.tracer.enabled:
+            return self._step_traced(batch, now)
+        self._hand_out(batch, self.server.predict(
             np.stack([t.payload for t in batch])))
+        return take
+
+    def _hand_out(self, batch: List[Ticket], logits) -> None:
+        logits = np.asarray(logits)
         for i, t in enumerate(batch):
             t.result = logits[i]
             self._complete(t)
-        return take
+
+    def _step_traced(self, batch: List[Ticket], t0: float) -> int:
+        """``step``'s dispatch with its phases as spans: ``step`` (from
+        admission; args ``batch``, a dispatch counter, and ``n``) holds
+        ``stack`` (the payloads into one array) and ``complete`` (the
+        results handed out); the server's own spans lie between.  Each
+        ticket's ``serve`` span carries the same ``batch`` id."""
+        tr = self.tracer
+        bid = next(self._batch_ids)
+        for t in batch:
+            t.batch = bid
+        t_stack = tr.clock()
+        images = np.stack([t.payload for t in batch])
+        t_stacked = tr.clock()
+        logits = np.asarray(self.server.predict(images))
+        t_complete = tr.clock()
+        self._hand_out(batch, logits)
+        t1 = tr.clock()
+        tr.span_at("step", t0, t1, cat="sched",
+                   args={"batch": bid, "n": len(batch)})
+        tr.span_at("stack", t_stack, t_stacked, cat="sched")
+        tr.span_at("complete", t_complete, t1, cat="sched")
+        return len(batch)
 
     def drain(self, max_steps: int = 10_000) -> int:
         """Serve until the queue is empty (flushing partial batches).

@@ -190,37 +190,74 @@ class ImageServer:
         n = images.shape[0]
         if n == 0:  # a drained request queue is not an error
             return np.zeros((0, self.api.cfg.n_classes), np.float32)
+        tr = self.tracer
         outs: List[np.ndarray] = []
         i = 0
         while i < n:
             bucket = self._bucket_for(n - i)
             take = min(n - i, bucket)
-            chunk = np.asarray(images[i:i + take])
-            if take < bucket:  # pad the tail up to the bucket
-                pad = np.zeros((bucket - take,) + chunk.shape[1:],
-                               chunk.dtype)
-                chunk = np.concatenate([chunk, pad])
-            tr = self.tracer
+            chunk = images[i:i + take]
             if tr.enabled:
-                # host dispatch (call returns while the device runs) vs
-                # device remainder (block_until_ready delta).  Blocking
-                # changes when the host waits, never the values — the
-                # bit-neutrality property tests/test_telemetry.py pins.
-                t0 = tr.clock()
-                y = self._fn(bucket)(self.params, jnp.asarray(chunk))
-                t1 = tr.clock()
-                jax.block_until_ready(y)
-                t2 = tr.clock()
-                tr.span_at("predict", t0, t2, cat="device",
-                           args={"bucket": bucket,
-                                 "dispatch_s": t1 - t0,
-                                 "device_s": t2 - t1})
-                self._m_device.observe(t2 - t0, phase="predict")
+                outs.append(self._predict_traced(tr, chunk, bucket))
             else:
-                y = self._fn(bucket)(self.params, jnp.asarray(chunk))
-            outs.append(np.asarray(y[:take]))
+                y = self._launch(self._put(chunk, bucket))
+                outs.append(self._fetch(y, take))
             i += take
         return np.concatenate(outs)
+
+    def _put(self, chunk: np.ndarray, bucket: int) -> jax.Array:
+        """Pad the chunk up to its bucket and start its copy to the
+        device (the copy runs on after this returns)."""
+        chunk = np.asarray(chunk)
+        if len(chunk) < bucket:
+            pad = np.zeros((bucket - len(chunk),) + chunk.shape[1:],
+                           chunk.dtype)
+            chunk = np.concatenate([chunk, pad])
+        return jnp.asarray(chunk)
+
+    def _launch(self, x: jax.Array) -> jax.Array:
+        return self._fn(x.shape[0])(self.params, x)
+
+    @staticmethod
+    def _fetch(y: jax.Array, take: int) -> np.ndarray:
+        return np.asarray(y[:take])
+
+    def _predict_traced(self, tr, chunk: np.ndarray, bucket: int
+                        ) -> np.ndarray:
+        """One chunk with its phases timed: an outer ``predict`` span
+        (``cat="device"``; ``dispatch_s`` = issue until the jitted call
+        returns, ``device_s`` = from there until the output is ready)
+        tiled by ``cat="host"`` children: ``put`` (pad and copy call),
+        ``launch`` (the jitted call), ``h2d_wait`` (until the input has
+        landed on the device; the step is already queued, so the device
+        sees no extra sync), ``device_wait`` (until the output is ready)
+        and ``fetch`` (output slice and copy to the host).  The outer
+        span's args also carry ``h2d_wait_s``, ``fetch_s`` and ``n``
+        (the chunk's images), so a reader of the ``predict`` spans alone
+        sees the phases and can tell whether any span went missing.
+        Waiting changes when the host blocks, never the values."""
+        t0 = tr.clock()
+        x = self._put(chunk, bucket)
+        t_put = tr.clock()
+        y = self._launch(x)
+        t1 = tr.clock()
+        x.block_until_ready()
+        t_h2d = tr.clock()
+        y.block_until_ready()
+        t2 = tr.clock()
+        out = self._fetch(y, len(chunk))
+        t3 = tr.clock()
+        tr.span_at("predict", t0, t3, cat="device",
+                   args={"bucket": bucket, "n": len(chunk),
+                         "dispatch_s": t1 - t0, "device_s": t2 - t1,
+                         "h2d_wait_s": t_h2d - t1, "fetch_s": t3 - t2})
+        tr.span_at("put", t0, t_put, cat="host", args={"bytes": x.nbytes})
+        tr.span_at("launch", t_put, t1, cat="host")
+        tr.span_at("h2d_wait", t1, t_h2d, cat="host")
+        tr.span_at("device_wait", t_h2d, t2, cat="host")
+        tr.span_at("fetch", t2, t3, cat="host")
+        self._m_device.observe(t2 - t0, phase="predict")
+        return out
 
     @property
     def compiled_buckets(self) -> tuple:

@@ -516,8 +516,10 @@ def device_time_split(tracer: Tracer, since: int = 0) -> Dict[str, float]:
     emit), optionally only events recorded after index ``since``.
 
     ``dispatch_s`` is host time until the async dispatch returned,
-    ``device_s`` the block-until-ready remainder, ``wall_s`` their sum
-    over all calls.  Per-phase wall totals land under ``phases``.
+    ``device_s`` the block-until-ready remainder, ``wall_s`` the spans'
+    whole length over all calls (for ``ImageServer.predict`` that also
+    holds the output's copy to the host).  Per-phase wall totals land
+    under ``phases``.
     """
     calls = 0
     wall = disp = dev = 0.0

@@ -208,6 +208,19 @@ class TestResNetPackedServe:
                                            impl="xla"))(packed, x)
         assert "rsqrt" not in str(jaxpr)
 
+    def test_every_layer_runs_under_its_named_scope(self, key):
+        """Each plan layer's ops carry its name in the compiled step's
+        ``op_name`` metadata, so device ops map back to their layer."""
+        import re
+        R, api, params, st, x, packed = self._setup(key)
+        text = jax.jit(lambda p_, x_: R.serve_forward(
+            api.cfg, p_, x_, api.policy, impl="xla")).lower(
+                packed, x).compile().as_text()
+        scopes = {part for op in re.findall(r'op_name="([^"]*)"', text)
+                  for part in op.split("/")}
+        names = {g.name for g in R.gemm_workload(api.cfg)}
+        assert len(names) == 21 and names <= scopes
+
     def test_fp_baseline_serve(self, key):
         """policy.quantize=False serves bf16 weights through the same path."""
         from repro.core.precision import PrecisionPolicy

@@ -38,7 +38,8 @@ from repro.runtime.scheduler import GenerateScheduler, ImageScheduler
 from repro.runtime.serve import Generator, ImageServer, pack_for_serving
 from repro.runtime.slo import HysteresisConfig, SLOScheduler
 from repro.runtime.telemetry import (GOLDEN_METRICS, NULL_METRICS,
-                                     NULL_TRACER, MetricsRegistry, Tracer,
+                                     NULL_TRACER, MetricsRegistry,
+                                     NullTracer, Tracer,
                                      as_metrics, as_tracer, declare_golden,
                                      device_time_split, device_timed,
                                      layer_attribution,
@@ -56,6 +57,22 @@ class FakeClock:
 
     def advance(self, dt):
         self.t += dt
+
+
+class TickClock:
+    """A clock that moves one second at every read."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def __call__(self):
+        self.reads += 1
+        return float(self.reads)
+
+
+class NoClock:
+    def __call__(self):
+        raise AssertionError("clock read")
 
 
 class FakeServer:
@@ -297,6 +314,33 @@ class TestSchedulerTracing:
             np.testing.assert_array_equal(a, b)
         assert plain_st == traced_st
 
+    def test_traced_step_holds_stack_and_tags_each_ticket(self):
+        clk, tr, mx, s, tickets = self._run(n=6)   # batches of 4 and 2
+        spans = {n: [e for e in tr.events if e[1] == n]
+                 for n in ("step", "stack", "complete")}
+        assert [e[6] for e in spans["step"]] == [{"batch": 0, "n": 4},
+                                                 {"batch": 1, "n": 2}]
+        for st, sk, cp in zip(spans["step"], spans["stack"],
+                              spans["complete"]):
+            assert st[2] == sk[2] == cp[2] == "sched"
+            # step holds stack, then the server's 0.25 s, then complete
+            assert st[4] <= sk[4] and sk[4] + sk[5] <= cp[4]
+            assert cp[4] + cp[5] <= st[4] + st[5]
+            assert st[5] == pytest.approx(0.25)
+        serve = {e[3]: e[6]["batch"] for e in tr.events if e[1] == "serve"}
+        assert serve == {t.id: t.batch for t in tickets}
+        assert sorted(serve.values()) == [0, 0, 0, 0, 1, 1]
+
+    def test_untraced_step_leaves_no_trace(self):
+        null = NullTracer()
+        null.clock = NoClock()
+        s = ImageScheduler(FakeServer(), max_wait_s=0.0, clock=FakeClock(),
+                           tracer=null)
+        tickets = [s.submit(_img(i)) for i in range(4)]
+        assert s.step() == 4
+        assert all(t.done and t.batch is None for t in tickets)
+        assert len(null.events) == 0
+
     def test_dropped_tickets_and_events_are_counted(self):
         clk = FakeClock()
         mx = MetricsRegistry()
@@ -446,6 +490,18 @@ class TestSLOTracing:
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture(scope="module")
+def packed_resnet():
+    from repro.models import resnet as R
+    api = configs.get("resnet18", reduced=True)
+    params = api.init_params(jax.random.PRNGKey(0))
+    state = R.init_bn_state(R.specs(api.cfg))
+    packed = R.pack_for_serve(api.cfg, params, state, api.policy)
+    imgs = np.random.default_rng(0).normal(
+        0.4, 0.5, (5, 32, 32, 3)).astype(np.float32)
+    return api, packed, imgs
+
+
 class TestDeviceTiming:
     def test_device_timed_wraps_and_splits(self):
         clk = FakeClock()
@@ -485,6 +541,43 @@ class TestDeviceTiming:
         assert split["calls"] == 2  # one bucket-4 + one padded bucket-2
         assert split["device_s"] >= 0.0
         assert validate_chrome_trace(tr.chrome_trace()) == []
+
+    def test_traced_predict_phases_tile_each_chunk(self, packed_resnet):
+        api, packed, imgs = packed_resnet
+        plain = ImageServer(api=api, params=packed, batch_buckets=(2, 4))
+        tr = Tracer(clock=TickClock())
+        traced = ImageServer(api=api, params=packed, batch_buckets=(2, 4),
+                             tracer=tr)
+        np.testing.assert_array_equal(plain.predict(imgs),
+                                      traced.predict(imgs))
+        outer = [e for e in tr.events if e[1] == "predict"]
+        kids = [e for e in tr.events if e[2] == "host"]
+        assert [e[2] for e in outer] == ["device", "device"]
+        assert [e[1] for e in kids] == ["put", "launch", "h2d_wait",
+                                        "device_wait", "fetch"] * 2
+        assert device_time_split(tr)["calls"] == 2
+        for o, ch in zip(outer, (kids[:5], kids[5:])):
+            # The children follow each other with no gap, from the start
+            # of the outer span to its end; every phase is one tick.
+            assert [c[4] for c in ch] == [o[4] + i for i in range(5)]
+            assert all(c[5] == 1.0 for c in ch) and o[5] == 5.0
+            assert o[6]["dispatch_s"] == ch[0][5] + ch[1][5]
+            assert o[6]["device_s"] == ch[2][5] + ch[3][5]
+            assert o[6]["h2d_wait_s"] == ch[2][5]
+            assert o[6]["fetch_s"] == ch[4][5]
+            assert ch[0][6] == {"bytes": o[6]["bucket"] * 32 * 32 * 3 * 4}
+        assert [o[6]["bucket"] for o in outer] == [4, 2]
+        assert [o[6]["n"] for o in outer] == [4, 1]
+
+    def test_untraced_predict_reads_no_clock(self, packed_resnet):
+        api, packed, imgs = packed_resnet
+        null = NullTracer()
+        null.clock = NoClock()
+        srv = ImageServer(api=api, params=packed, batch_buckets=(2, 4),
+                          tracer=null)
+        plain = ImageServer(api=api, params=packed, batch_buckets=(2, 4))
+        np.testing.assert_array_equal(srv.predict(imgs), plain.predict(imgs))
+        assert len(null.events) == 0
 
     def test_traced_generator_is_bit_identical(self, lm_generator):
         api = lm_generator.api

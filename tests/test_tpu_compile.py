@@ -127,6 +127,35 @@ def test_conv_mpmm(one_chip, hw, c_in, c_out, stride, w_bits, k):
              ((BATCH, ho, wo, n), jnp.bfloat16))
 
 
+def test_named_layer_keeps_its_kernel_name(one_chip, monkeypatch):
+    """A layer served under its plan name runs inside that name's scope,
+    and its Mosaic call keeps the kernel's own name (``mpmm.<n>``),
+    which the device trace and the benchmark's kernel routing read."""
+    import re
+
+    from repro import configs
+    from repro.core import flags
+    from repro.nn import quantized as Q
+    monkeypatch.setattr(flags, "default_interpret", lambda: False)
+    api = configs.get("resnet18", reduced=True)
+    raw = api.init_params(jax.random.PRNGKey(0))["fc"]
+    fc = Q.pack_qlinear(raw, api.policy, layer_class="boundary", name="fc")
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), fc)
+    x = jax.ShapeDtypeStruct((BATCH, raw["w"].shape[0]), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, a: Q.qlinear_serve_apply(
+        p, a, api.policy, layer_class="boundary", impl="pallas",
+        name="fc")).lower(shapes, x).compile().as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and re.match(r"\s*(ROOT )?%?\w", l)]
+    assert calls
+    for line in calls:
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
+        assert name.split(".")[0] == "mpmm"
+        assert "/fc/" in re.search(r'op_name="([^"]*)"', line).group(1)
+
+
 @pytest.mark.parametrize("bits", [2, 4], ids=["kv2", "kv4"])
 def test_flash_fwd_packed_granite_8b(one_chip, bits):
     # granite-8b attention widths: 32 query heads over 8 KV heads, D=128.
