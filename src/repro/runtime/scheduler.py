@@ -13,7 +13,10 @@ Two schedulers, one per family shape:
     buckets — a batch dispatches as soon as the largest bucket fills, or
     when the oldest request has waited ``max_wait_s`` (classic
     batching-window policy), so latency is bounded while throughput
-    comes from full buckets.
+    comes from full buckets.  When a second full bucket waits behind
+    the batch being served, its ``predict`` starts on a worker thread
+    before the first is handed out (dispatch-ahead), so the next
+    batch's stack and host-to-device copy overlap this one's compute.
 
   * ``GenerateScheduler`` (LM): requests are (prompt, n_new) generation
     jobs of different lengths and lifetimes.  The scheduler keeps a
@@ -42,6 +45,7 @@ it was served alone, coalesced, or interleaved mid-decode.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
 import itertools
 import random
@@ -165,6 +169,7 @@ class _SchedulerBase:
         self.degraded = 0    # served at a lower-bit frontier point
         self.retried = 0     # transient-failure redispatches
         self.failed = 0      # retries exhausted / drive loop aborted
+        self.dispatched_ahead = 0  # batches started while one was in flight
         self.served: Deque[Ticket] = collections.deque(maxlen=history)
         self.events: Deque[Tuple[int, str, Tuple[int, ...]]] = \
             collections.deque(maxlen=max(4 * history, 4096))
@@ -397,6 +402,8 @@ class _SchedulerBase:
             "degraded": float(self.degraded),
             "retried": float(self.retried),
             "failed": float(self.failed),
+            # dispatch-ahead (live only on ImageScheduler)
+            "dispatched_ahead": float(self.dispatched_ahead),
             "mean_latency_s": self._lat_sum / n if n else 0.0,
             "max_latency_s": self._lat_max,
             "mean_queue_wait_s": self._qw_sum / n if n else 0.0,
@@ -439,6 +446,17 @@ class ImageScheduler(_SchedulerBase):
     ``max_wait_s`` (then the smallest bucket that fits the stragglers
     is used — the server pads the remainder).  ``step(flush=True)``
     dispatches whatever is queued regardless of the window (drain).
+
+    Dispatch-ahead: while one batch is in flight and a full largest
+    bucket is queued behind it, ``step`` stacks that bucket and starts
+    its ``predict`` on a worker thread before it waits for the oldest
+    batch, so at most two batches are in flight and the next one's
+    input path runs while the device computes this one.  Whether it
+    engages is read from the queue's depth: with no second full bucket
+    queued, ``predict`` runs inline on the caller's thread and no thread
+    is made.  Workers run ``predict`` alone; clock reads, logs and
+    hand-out stay on the caller's thread, in FIFO order.  ``pending``
+    counts queued and in-flight requests.
     """
 
     def __init__(self, server, *, max_queue: int = 256,
@@ -453,6 +471,12 @@ class ImageScheduler(_SchedulerBase):
         self.dispatched_batches: Deque[int] = collections.deque(
             maxlen=history)
         self._batch_ids = itertools.count()
+        # Dispatched batches not yet handed out, oldest first: (tickets,
+        # logits or the worker's future); the workers are made at the
+        # first dispatch-ahead.
+        self._in_flight: Deque[Tuple[List[Ticket], Any]] = \
+            collections.deque()
+        self._workers: Optional[concurrent.futures.ThreadPoolExecutor] = None
         # Expected request shape: from the server's model config when it
         # carries one (ImageServer), else locked to the first request.
         cfg = getattr(getattr(server, "api", None), "cfg", None)
@@ -478,66 +502,120 @@ class ImageScheduler(_SchedulerBase):
                    t_submit=self.clock())
         return self._enqueue(t)
 
+    @property
+    def pending(self) -> int:
+        return len(self._queue) + sum(len(b) for b, _ in self._in_flight)
+
     def step(self, flush: bool = False) -> int:
-        """Dispatch at most one batch; returns requests completed."""
+        """Complete at most one batch; returns requests completed.  A
+        step dispatches at most one batch, two where it starts
+        dispatch-ahead with nothing in flight.
+
+        With nothing in flight, the admission rule picks a batch; it runs
+        inline unless a full largest bucket is queued behind it.  With
+        one batch in flight and a full largest bucket queued, that bucket
+        starts on a worker.  Then the oldest batch in flight is waited
+        for and handed out; an exception its ``predict`` raised is raised
+        here."""
         self._tick += 1
-        if not self._queue:
-            return 0
-        oldest = self.clock() - self._queue[0].t_submit
-        if (len(self._queue) < self.buckets[-1] and oldest < self.max_wait_s
-                and not flush):
-            return 0  # keep coalescing inside the batching window
-        take = min(len(self._queue), self.buckets[-1])
+        top = self.buckets[-1]
+        t0 = None
+        if not self._in_flight:
+            if not self._queue:
+                return 0
+            oldest = self.clock() - self._queue[0].t_submit
+            if (len(self._queue) < top and oldest < self.max_wait_s
+                    and not flush):
+                return 0  # keep coalescing inside the batching window
+            take = min(len(self._queue), top)
+            t0 = self._dispatch(
+                take, on_worker=len(self._queue) >= take + top)
+        ahead = len(self._in_flight) == 1 and len(self._queue) >= top
+        if ahead:
+            self.dispatched_ahead += 1
+            t_ahead = self._dispatch(top, on_worker=True)
+            t0 = t_ahead if t0 is None else t0
+        return self._hand_out_oldest(t0, ahead)
+
+    def _dispatch(self, take: int, on_worker: bool) -> float:
+        """Admit the queue's first ``take`` requests, stack them and
+        start their ``predict``: on a worker when another batch will be
+        dispatched before this one is handed out, else inline.  Returns
+        the admission time."""
         batch = [self._queue.popleft() for _ in range(take)]
         now = self.clock()
         for t in batch:
             t.t_admit = now
         self._log("dispatch", batch)
         self.dispatched_batches.append(take)
-        if self.tracer.enabled:
-            return self._step_traced(batch, now)
-        self._hand_out(batch, self.server.predict(
-            np.stack([t.payload for t in batch])))
-        return take
+        tr = self.tracer
+        if tr.enabled:
+            bid = next(self._batch_ids)
+            for t in batch:
+                t.batch = bid
+            t_stack = tr.clock()
+        images = np.stack([t.payload for t in batch])
+        if tr.enabled:
+            tr.span_at("stack", t_stack, tr.clock(), cat="sched")
+        if not on_worker:
+            out = self.server.predict(images)
+        else:
+            if self._workers is None:
+                self._workers = concurrent.futures.ThreadPoolExecutor(
+                    max_workers=2, thread_name_prefix="image-predict")
+            out = self._workers.submit(self.server.predict, images)
+        self._in_flight.append((batch, out))
+        return now
 
-    def _hand_out(self, batch: List[Ticket], logits) -> None:
-        logits = np.asarray(logits)
+    def _hand_out_oldest(self, t0: Optional[float], ahead: bool) -> int:
+        """Wait for the oldest batch in flight and hand its results out.
+
+        Traced, the step is a ``step`` span (from admission, or from the
+        wait when the step admitted nothing; args ``batch``, the
+        dispatch counter of the batch handed out, its ``n``, and
+        ``ahead``: 1 when the step dispatched ahead) that holds the
+        step's ``stack`` spans and ``complete`` (the results handed
+        out); the server's own spans lie between.  Each ticket's
+        ``serve`` span carries the same ``batch`` id."""
+        tr = self.tracer
+        if tr.enabled and t0 is None:
+            t0 = tr.clock()
+        batch, out = self._in_flight.popleft()
+        if isinstance(out, concurrent.futures.Future):
+            out = out.result()
+        if tr.enabled:
+            t_complete = tr.clock()
+        logits = np.asarray(out)
         for i, t in enumerate(batch):
             t.result = logits[i]
             self._complete(t)
-
-    def _step_traced(self, batch: List[Ticket], t0: float) -> int:
-        """``step``'s dispatch with its phases as spans: ``step`` (from
-        admission; args ``batch``, a dispatch counter, and ``n``) holds
-        ``stack`` (the payloads into one array) and ``complete`` (the
-        results handed out); the server's own spans lie between.  Each
-        ticket's ``serve`` span carries the same ``batch`` id."""
-        tr = self.tracer
-        bid = next(self._batch_ids)
-        for t in batch:
-            t.batch = bid
-        t_stack = tr.clock()
-        images = np.stack([t.payload for t in batch])
-        t_stacked = tr.clock()
-        logits = np.asarray(self.server.predict(images))
-        t_complete = tr.clock()
-        self._hand_out(batch, logits)
-        t1 = tr.clock()
-        tr.span_at("step", t0, t1, cat="sched",
-                   args={"batch": bid, "n": len(batch)})
-        tr.span_at("stack", t_stack, t_stacked, cat="sched")
-        tr.span_at("complete", t_complete, t1, cat="sched")
+        if tr.enabled:
+            t1 = tr.clock()
+            tr.span_at("step", t0, t1, cat="sched",
+                       args={"batch": batch[0].batch, "n": len(batch),
+                             "ahead": int(ahead)})
+            tr.span_at("complete", t_complete, t1, cat="sched")
         return len(batch)
 
+    def _pending_tickets(self) -> List[Ticket]:
+        return ([t for b, _ in self._in_flight for t in b]
+                + list(self._queue))
+
+    def _fail_pending(self, op: str, max_steps: int) -> RuntimeError:
+        err = super()._fail_pending(op, max_steps)
+        self._in_flight.clear()  # their results are never handed out
+        return err
+
     def drain(self, max_steps: int = 10_000) -> int:
-        """Serve until the queue is empty (flushing partial batches).
+        """Serve until nothing is queued or in flight (flushing partial
+        batches).
 
         If the loop does not converge within ``max_steps``, the pending
         tickets are FAILED (outcome ``'failed'``) rather than stranded,
         and the raised error lists their ids and ages."""
         n = 0
         for _ in range(max_steps):
-            if not self._queue:
+            if not self.pending:
                 return n
             n += self.step(flush=True)
         raise self._fail_pending("drain", max_steps)

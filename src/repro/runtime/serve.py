@@ -28,6 +28,7 @@ w8/w4/w2 plans); with ``mesh=None`` nothing changes at all.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Dict, List, Optional
 
@@ -151,6 +152,9 @@ class ImageServer:
                                          part.replicated(self.mesh))
         self.batch_buckets = tuple(sorted(set(self.batch_buckets)))
         self._fns: Dict[int, Any] = {}
+        # Traced chunks in progress, counted across the caller's threads.
+        self._lock = threading.Lock()
+        self._chunks_in_progress = 0
         self.tracer = as_tracer(self.tracer)
         self.metrics = as_metrics(self.metrics)
         self._m_device = self.metrics.histogram("repro_device_time_seconds")
@@ -235,22 +239,33 @@ class ImageServer:
         span's args also carry ``h2d_wait_s``, ``fetch_s`` and ``n``
         (the chunk's images), so a reader of the ``predict`` spans alone
         sees the phases and can tell whether any span went missing.
+        ``overlap`` counts the chunks of other ``predict`` calls of this
+        server in progress when this chunk's ``put`` began (a scheduler
+        that keeps two batches in flight calls from two threads).
         Waiting changes when the host blocks, never the values."""
-        t0 = tr.clock()
-        x = self._put(chunk, bucket)
-        t_put = tr.clock()
-        y = self._launch(x)
-        t1 = tr.clock()
-        x.block_until_ready()
-        t_h2d = tr.clock()
-        y.block_until_ready()
-        t2 = tr.clock()
-        out = self._fetch(y, len(chunk))
-        t3 = tr.clock()
+        with self._lock:
+            overlap = self._chunks_in_progress
+            self._chunks_in_progress += 1
+        try:
+            t0 = tr.clock()
+            x = self._put(chunk, bucket)
+            t_put = tr.clock()
+            y = self._launch(x)
+            t1 = tr.clock()
+            x.block_until_ready()
+            t_h2d = tr.clock()
+            y.block_until_ready()
+            t2 = tr.clock()
+            out = self._fetch(y, len(chunk))
+            t3 = tr.clock()
+        finally:
+            with self._lock:
+                self._chunks_in_progress -= 1
         tr.span_at("predict", t0, t3, cat="device",
                    args={"bucket": bucket, "n": len(chunk),
                          "dispatch_s": t1 - t0, "device_s": t2 - t1,
-                         "h2d_wait_s": t_h2d - t1, "fetch_s": t3 - t2})
+                         "h2d_wait_s": t_h2d - t1, "fetch_s": t3 - t2,
+                         "overlap": overlap})
         tr.span_at("put", t0, t_put, cat="host", args={"bytes": x.nbytes})
         tr.span_at("launch", t_put, t1, cat="host")
         tr.span_at("h2d_wait", t1, t_h2d, cat="host")

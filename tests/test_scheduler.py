@@ -8,6 +8,7 @@ a dedicated ``Generator`` run and independent of arrival order / batch
 composition — on a real packed granite-shape model.
 """
 import dataclasses
+import threading
 
 import jax
 import numpy as np
@@ -40,6 +41,30 @@ class FakeServer:
     def predict(self, images):
         self.calls.append(images.shape[0])
         return images.sum(axis=(1, 2, 3), keepdims=True)
+
+
+class GatedServer(FakeServer):
+    """``predict`` of the batch whose first image holds value ``v`` waits
+    for ``gates[v]`` (when one is given) and sets ``began[v]`` first;
+    the thread each batch ran on is recorded."""
+
+    def __init__(self, firsts, buckets=(4,), gated=True, fail=()):
+        super().__init__(buckets)
+        self.began = {v: threading.Event() for v in firsts}
+        self.gates = {v: threading.Event() for v in firsts} if gated \
+            else {}
+        self.fail = set(fail)
+        self.threads = {}
+
+    def predict(self, images):
+        v = float(images[0, 0, 0, 0])
+        self.threads[v] = threading.get_ident()
+        self.began[v].set()
+        if v in self.gates:
+            assert self.gates[v].wait(10), f"batch {v} never released"
+        if v in self.fail:
+            raise RuntimeError(f"batch {v} failed")
+        return super().predict(images)
 
 
 def _img(v, hw=2):
@@ -158,6 +183,107 @@ class TestImageScheduler:
         a, b = outs.values()
         for i in range(6):
             np.testing.assert_array_equal(a[i], b[i])
+
+
+class TestDispatchAhead:
+    """A second full largest bucket queued behind the batch being served
+    starts on a worker before that batch is handed out."""
+
+    def test_next_batch_starts_before_the_first_is_handed_out(self):
+        srv = GatedServer(firsts=(0.0, 4.0, 8.0))
+        s = ImageScheduler(srv, max_wait_s=0.0, clock=FakeClock())
+        tickets = [s.submit(_img(i)) for i in range(12)]  # 3 full buckets
+        done = []
+        first = threading.Thread(target=lambda: done.append(s.step()))
+        first.start()
+        try:
+            assert srv.began[0.0].wait(10) and srv.began[4.0].wait(10)
+            assert not any(t.done for t in tickets)
+            assert not srv.began[8.0].is_set()   # at most two in flight
+        finally:
+            for g in srv.gates.values():
+                g.set()
+            first.join(10)
+        assert not first.is_alive() and done == [4]
+        assert [t.done for t in tickets] == [True] * 4 + [False] * 8
+        assert s.pending == 8 and s.stats()["pending"] == 8.0
+        assert srv.threads[0.0] != threading.get_ident()
+        while s.pending:
+            s.step()
+        assert all(t.done for t in tickets)
+        assert s.stats()["dispatched_ahead"] == 2.0
+
+    def test_same_results_as_one_bucket_at_a_time(self):
+        imgs = [_img(i) for i in range(14)]    # 4 + 4 + 4 + 2 stragglers
+
+        def serve(ahead):
+            srv = FakeServer(buckets=(2, 4))
+            s = ImageScheduler(srv, max_wait_s=0.0, clock=FakeClock())
+            tickets, counts = [], []
+            if ahead:                          # every bucket queued at once
+                tickets = [s.submit(im) for im in imgs]
+                while s.pending:
+                    counts.append(s.step())
+            else:                              # never a second full bucket
+                for k in range(0, len(imgs), 4):
+                    tickets += [s.submit(im) for im in imgs[k:k + 4]]
+                    counts.append(s.step())
+            order = [t.id for t in s.served]
+            return (counts, [t.result for t in tickets], order, srv.calls,
+                    s.stats()["dispatched_ahead"])
+
+        a, b = serve(True), serve(False)
+        assert a[0] == b[0] == [4, 4, 4, 2]
+        for x, y in zip(a[1], b[1]):
+            np.testing.assert_array_equal(x, y)
+        assert a[2] == b[2] == list(range(14))  # FIFO hand-out
+        assert a[3] == b[3] == [4, 4, 4, 2]
+        assert (a[4], b[4]) == (2.0, 0.0)
+
+    def test_one_batch_queued_runs_inline_without_a_thread(self):
+        srv = GatedServer(firsts=(0.0, 8.0), buckets=(4, 8), gated=False)
+        s = ImageScheduler(srv, max_wait_s=0.0, clock=FakeClock())
+        tickets = [s.submit(_img(i)) for i in range(11)]  # 8 + 3
+        assert s.step() == 8
+        assert s._workers is None and s.pending == 3
+        assert s.step() == 3
+        assert all(t.done for t in tickets)
+        assert set(srv.threads.values()) == {threading.get_ident()}
+        assert s.stats()["dispatched_ahead"] == 0.0 and s._workers is None
+
+    def test_pending_counts_in_flight_and_nothing_is_stranded(self):
+        srv = FakeServer(buckets=(4,))
+        s = ImageScheduler(srv, max_wait_s=0.0, clock=FakeClock())
+        tickets = []
+        for _ in range(5):        # a closed loop: top up to 2 buckets
+            while s.pending < 8:
+                tickets.append(s.submit(_img(len(tickets))))
+            s.step()
+            assert s.pending == 4 and len(s._in_flight) == 1
+        while s.pending:
+            s.step()
+        assert len(tickets) == 24 and all(t.done for t in tickets)
+        assert s.stats()["served"] == 24.0 and s.stats()["pending"] == 0.0
+        assert [t.result.item() for t in tickets] == \
+            [12.0 * i for i in range(24)]
+        tickets = [s.submit(_img(i)) for i in range(9)]
+        assert s.drain() == 9 and all(t.done for t in tickets)
+
+    @pytest.mark.parametrize("bad", [0.0, 4.0])
+    def test_worker_error_surfaces_from_the_step_that_waits(self, bad):
+        srv = GatedServer(firsts=(0.0, 4.0, 8.0), gated=False, fail=(bad,))
+        s = ImageScheduler(srv, max_wait_s=0.0, clock=FakeClock())
+        tickets = [s.submit(_img(i)) for i in range(12)]
+        if bad == 4.0:
+            assert s.step() == 4                # batch 0 is fine
+        with pytest.raises(RuntimeError, match=f"batch {bad} failed"):
+            s.step()
+        assert srv.threads[bad] != threading.get_ident()
+        while s.pending:                        # the rest still serve
+            s.step()
+        k = int(bad)
+        assert [t.done for t in tickets] == \
+            [i not in range(k, k + 4) for i in range(12)]
 
 
 @pytest.fixture(scope="module")

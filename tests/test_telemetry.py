@@ -24,6 +24,8 @@ logits.  The contracts pinned:
 """
 import dataclasses
 import json
+import sys
+import threading
 
 import jax
 import numpy as np
@@ -318,8 +320,8 @@ class TestSchedulerTracing:
         clk, tr, mx, s, tickets = self._run(n=6)   # batches of 4 and 2
         spans = {n: [e for e in tr.events if e[1] == n]
                  for n in ("step", "stack", "complete")}
-        assert [e[6] for e in spans["step"]] == [{"batch": 0, "n": 4},
-                                                 {"batch": 1, "n": 2}]
+        assert [e[6] for e in spans["step"]] == [
+            {"batch": 0, "n": 4, "ahead": 0}, {"batch": 1, "n": 2, "ahead": 0}]
         for st, sk, cp in zip(spans["step"], spans["stack"],
                               spans["complete"]):
             assert st[2] == sk[2] == cp[2] == "sched"
@@ -330,6 +332,21 @@ class TestSchedulerTracing:
         serve = {e[3]: e[6]["batch"] for e in tr.events if e[1] == "serve"}
         assert serve == {t.id: t.batch for t in tickets}
         assert sorted(serve.values()) == [0, 0, 0, 0, 1, 1]
+
+    def test_traced_dispatch_ahead_marks_its_steps(self):
+        tr = Tracer(clock=FakeClock())
+        s = ImageScheduler(FakeServer(), max_wait_s=0.0, clock=FakeClock(),
+                           tracer=tr)
+        tickets = [s.submit(_img(i)) for i in range(12)]  # 3 full buckets
+        assert [s.step() for _ in range(3)] == [4, 4, 4]
+        steps = [e[6] for e in tr.events if e[1] == "step"]
+        assert steps == [{"batch": 0, "n": 4, "ahead": 1},
+                         {"batch": 1, "n": 4, "ahead": 1},
+                         {"batch": 2, "n": 4, "ahead": 0}]
+        assert [e[1] for e in tr.events if e[2] == "sched"].count(
+            "stack") == 3
+        assert [t.batch for t in tickets] == [0] * 4 + [1] * 4 + [2] * 4
+        assert s.stats()["dispatched_ahead"] == 2.0
 
     def test_untraced_step_leaves_no_trace(self):
         null = NullTracer()
@@ -369,7 +386,7 @@ class TestStatsSchemaParity:
 
     GOLDEN_KEYS = {
         "served", "rejected", "pending", "expired", "degraded", "retried",
-        "failed", "mean_latency_s", "max_latency_s", "mean_queue_wait_s",
+        "failed", "dispatched_ahead", "mean_latency_s", "max_latency_s", "mean_queue_wait_s",
         "p50_latency_s", "p95_latency_s", "p99_latency_s",
         "dropped_events", "dropped_tickets",
         "level", "throttled", "transitions",
@@ -568,6 +585,7 @@ class TestDeviceTiming:
             assert ch[0][6] == {"bytes": o[6]["bucket"] * 32 * 32 * 3 * 4}
         assert [o[6]["bucket"] for o in outer] == [4, 2]
         assert [o[6]["n"] for o in outer] == [4, 1]
+        assert [o[6]["overlap"] for o in outer] == [0, 0]  # one caller
 
     def test_untraced_predict_reads_no_clock(self, packed_resnet):
         api, packed, imgs = packed_resnet
@@ -578,6 +596,75 @@ class TestDeviceTiming:
         plain = ImageServer(api=api, params=packed, batch_buckets=(2, 4))
         np.testing.assert_array_equal(srv.predict(imgs), plain.predict(imgs))
         assert len(null.events) == 0
+
+    def test_concurrent_predict_counts_overlap(self, packed_resnet):
+        """A chunk whose ``put`` begins while another caller's chunk is
+        in progress carries ``overlap`` 1; the values never change."""
+        api, packed, imgs = packed_resnet
+        tr = Tracer()
+        srv = ImageServer(api=api, params=packed, batch_buckets=(2, 4),
+                          tracer=tr)
+        plain = ImageServer(api=api, params=packed, batch_buckets=(2, 4))
+        in_fetch, release = threading.Event(), threading.Event()
+        fetch = srv._fetch
+
+        def held_fetch(y, take):
+            if not in_fetch.is_set():
+                in_fetch.set()
+                assert release.wait(30)
+            return fetch(y, take)
+
+        srv._fetch = held_fetch
+        first = []
+        t = threading.Thread(target=lambda: first.append(
+            srv.predict(imgs[:4])))
+        t.start()
+        try:
+            assert in_fetch.wait(30)
+            second = srv.predict(imgs[4:])
+        finally:
+            release.set()
+            t.join(30)
+        assert not t.is_alive() and len(first) == 1
+        np.testing.assert_array_equal(
+            np.concatenate([first[0], second]), plain.predict(imgs))
+        overlap = {e[6]["n"]: e[6]["overlap"] for e in tr.events
+                   if e[1] == "predict"}
+        assert overlap == {4: 0, 1: 1}
+        assert srv._chunks_in_progress == 0
+
+    def test_overlap_count_survives_many_callers(self, packed_resnet):
+        """Stress: more callers than cores, a short switch interval; a
+        lost update would leave the count off zero or out of range."""
+        api, packed, imgs = packed_resnet
+        tr = Tracer(capacity=1 << 16)
+        srv = ImageServer(api=api, params=packed, batch_buckets=(1,),
+                          tracer=tr)
+        callers, calls = 16, 3
+        errors = []
+
+        def work():
+            try:
+                for _ in range(calls):
+                    srv.predict(imgs[:1])
+            except Exception as e:  # reported below, with the others
+                errors.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=work) for _ in range(callers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads) and errors == []
+        overlaps = [e[6]["overlap"] for e in tr.events if e[1] == "predict"]
+        assert len(overlaps) == callers * calls
+        assert all(0 <= o < callers for o in overlaps)
+        assert srv._chunks_in_progress == 0
 
     def test_traced_generator_is_bit_identical(self, lm_generator):
         api = lm_generator.api
